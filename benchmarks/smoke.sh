@@ -31,6 +31,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# a CPU gate: it stays off any chip (python chip_smoke.py is the chip run)
+export JAX_PLATFORMS=cpu
 
 echo "== workload smoke: 5s scenario on a 5-node cluster =="
 python - <<'EOF'

@@ -80,6 +80,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.obs import format_profile_report  # noqa: E402
 from repro.workload import (ExperimentConfig, WorkloadSpec,  # noqa: E402
                             run_cassandra_breakdown, run_cassandra_profiled,
@@ -1089,6 +1090,7 @@ def main(argv=None) -> int:
                     help="pretty-print the breakdown block of --out "
                          "(stage table + slowest traces) and exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.report:
         return print_report(args.out)

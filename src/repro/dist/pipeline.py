@@ -8,7 +8,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -90,9 +89,9 @@ def gpipe(stage_fn: Callable, mesh, axis: str = "pipe") -> Callable:
                                        jnp.arange(ticks))
             return out
 
-        mapped = shard_map(device_body, mesh=mesh,
-                           in_specs=(P(axis), P()), out_specs=P(axis),
-                           check_rep=False)
+        mapped = jax.shard_map(device_body, mesh=mesh,
+                               in_specs=(P(axis), P()), out_specs=P(axis),
+                               check_vma=False)
         stacked = mapped(Ws, x)       # (n_devices * n_micro, mb, ...)
         return stacked[-n_micro:]     # only the last stage's buffer is real
 

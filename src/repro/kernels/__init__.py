@@ -4,10 +4,7 @@
 
 
 def tpu_compiler_params(dimension_semantics: tuple):
-    """Pallas-TPU CompilerParams across jax renames (TPUCompilerParams
-    pre-0.6, CompilerParams after).  Raises if pallas.tpu is unavailable;
+    """Pallas-TPU CompilerParams.  Raises if pallas.tpu is unavailable;
     callers that must run on CPU wrap this in try/except."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

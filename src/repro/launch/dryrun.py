@@ -181,10 +181,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, out_dir: Path,
     for depth in (L1, L2):
         cfg_a = cfg.scaled(num_layers=depth, scan_layers=False)
         compiled_a, _, _ = _lower(cfg_a, shape, mesh, pol, weight_quant)
-        ca = compiled_a.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-            ca = ca[0] if ca else {}
-        costs.append(ca)
+        costs.append(compiled_a.cost_analysis() or {})
         colls.append(hlo_mod.parse_collectives(compiled_a.as_text(), chips))
         del compiled_a
 

@@ -9,6 +9,14 @@ while smoke tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """`jax.make_mesh` with Auto axes.  The sharding layer steers layouts
+    with `with_sharding_constraint` and bare `PartitionSpec`s, which
+    Explicit axes (`jax.make_mesh`'s default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for_devices(n_devices: int, model_parallel: int = 1,
@@ -28,4 +36,4 @@ def make_mesh_for_devices(n_devices: int, model_parallel: int = 1,
     model = min(model_parallel, n_devices)
     while n_devices % model:
         model -= 1
-    return jax.make_mesh((n_devices // model, model), axes)
+    return make_mesh((n_devices // model, model), axes)

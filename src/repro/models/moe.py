@@ -132,10 +132,6 @@ def _moe_gspmd(params, x: jax.Array, cfg: ModelConfig):
 
 def _moe_shard_map(params, x: jax.Array, cfg: ModelConfig, ctx):
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
 
     mesh = ctx.mesh
     pol = ctx.pol
@@ -190,10 +186,7 @@ def _moe_shard_map(params, x: jax.Array, cfg: ModelConfig, ctx):
         in_specs=(P(), P(ep, None, tp), P(ep, None, tp), P(ep, tp, None),
                   xspec),
         out_specs=(yspec, P()))
-    try:
-        mapped = shard_map(body, mesh=mesh, check_vma=False, **specs)
-    except TypeError:  # pre-0.6 jax spells the kwarg check_rep
-        mapped = shard_map(body, mesh=mesh, check_rep=False, **specs)
+    mapped = jax.shard_map(body, mesh=mesh, check_vma=False, **specs)
     out = mapped(params["router"], params["w_gate"], params["w_up"],
                  params["w_down"], x)
     y, aux = out

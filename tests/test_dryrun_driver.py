@@ -3,11 +3,15 @@ subprocess (512 host devices, production 16×16 mesh), asserting the JSON
 artifact has coherent roofline terms."""
 
 import json
+import os
 import subprocess
 import sys
 import tempfile
 import textwrap
 from pathlib import Path
+
+# children stay on the CPU: the parent process may hold the chip
+CPU_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 SCRIPT = textwrap.dedent("""
     import sys, json
@@ -31,7 +35,7 @@ def test_dryrun_cell_end_to_end():
     with tempfile.TemporaryDirectory() as td:
         r = subprocess.run([sys.executable, "-c", SCRIPT, td],
                            capture_output=True, text=True, timeout=900,
-                           cwd=".")
+                           cwd=".", env=CPU_ENV)
         assert "DRYRUN_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]
         cells = list(Path(td).glob("*.json"))
         assert len(cells) == 1
@@ -55,5 +59,5 @@ def test_skip_cell_is_recorded():
             'print("DRYRUN_OK", r["dominant"])', 'print("DRYRUN_OK skip")')
         r = subprocess.run([sys.executable, "-c", script, td],
                            capture_output=True, text=True, timeout=300,
-                           cwd=".")
+                           cwd=".", env=CPU_ENV)
         assert "DRYRUN_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]

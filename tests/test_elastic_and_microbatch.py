@@ -1,6 +1,7 @@
 """Elastic re-meshing (restore onto a different mesh) and gradient
 accumulation equivalence."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -14,6 +15,9 @@ from repro.configs import smoke_config
 from repro.data.pipeline import DataConfig, TokenStream
 from repro.train.optim import OptimizerConfig
 from repro.train.step import TrainConfig, init_train_state, make_train_step
+
+# children stay on the CPU: the parent process may hold the chip
+CPU_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 
 def test_microbatch_accumulation_matches_full_batch():
@@ -63,6 +67,7 @@ ELASTIC_SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import smoke_config
     from repro.dist.sharding import MeshContext, ShardingPolicy
+    from repro.launch.mesh import make_mesh
     from repro.checkpoint.store import SpinnakerCheckpointStore, StoreConfig
     from repro.data.pipeline import DataConfig, TokenStream
     from repro.train.optim import OptimizerConfig
@@ -92,7 +97,7 @@ ELASTIC_SCRIPT = textwrap.dedent("""
         return state, losses
 
     # phase 1: 8 devices as (4, 2)
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
     state, l1 = run_on_mesh(mesh_a, state, 0, 3)
 
@@ -123,5 +128,6 @@ def test_elastic_restart_on_smaller_mesh_subprocess():
     survivors: losses must match the uninterrupted run (restore is by
     logical key, resharding-safe)."""
     r = subprocess.run([sys.executable, "-c", ELASTIC_SCRIPT],
-                       capture_output=True, text=True, timeout=900, cwd=".")
+                       capture_output=True, text=True, timeout=900, cwd=".",
+                       env=CPU_ENV)
     assert "ELASTIC_OK" in r.stdout, r.stdout + r.stderr

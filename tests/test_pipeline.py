@@ -1,11 +1,15 @@
 """Pipeline parallelism: GPipe schedule must be exact vs the sequential
 stack (runs on 8 host devices in a subprocess)."""
 
+import os
 import subprocess
 import sys
 import textwrap
 
 from repro.dist.pipeline import bubble_fraction, pp_vs_dp_napkin
+
+# children stay on the CPU: the parent process may hold the chip
+CPU_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 
 def test_bubble_fraction():
@@ -28,8 +32,9 @@ PIPE_SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist.pipeline import gpipe
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("pipe", "model"))
+    mesh = make_mesh((4, 2), ("pipe", "model"))
     D = 32
     n_stages, layers_per_stage = 4, 2
     rng = np.random.default_rng(0)
@@ -64,5 +69,6 @@ PIPE_SCRIPT = textwrap.dedent("""
 
 def test_gpipe_exact_vs_sequential_subprocess():
     r = subprocess.run([sys.executable, "-c", PIPE_SCRIPT],
-                       capture_output=True, text=True, timeout=600, cwd=".")
+                       capture_output=True, text=True, timeout=600, cwd=".",
+                       env=CPU_ENV)
     assert "PIPE_OK" in r.stdout, r.stdout + r.stderr

@@ -1,5 +1,6 @@
 """Weight-only quantization + distribution-layer unit tests."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -14,6 +15,9 @@ from repro.models import decode_step, forward, init_cache, init_params
 from repro.models.quant import (dequantize_tree, is_quantized,
                                 quantize_tree, quantize_weight, wcast)
 from repro.launch.shapes import make_batch, make_decode_tokens
+
+# children stay on the CPU: the parent process may hold the chip
+CPU_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 
 def test_quantize_roundtrip_error_bounded():
@@ -77,12 +81,13 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import smoke_config
     from repro.dist.sharding import MeshContext, ShardingPolicy
+    from repro.launch.mesh import make_mesh
     from repro.models.moe import init_moe, moe_ffn
 
     cfg = smoke_config("kimi-k2-1t-a32b").scaled(
         dtype="float32", num_experts=8, moe_d_ff=64, capacity_factor=8.0,
         shared_expert_d_ff=0)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     pol = ShardingPolicy.for_mesh(mesh)
     params = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     rng = np.random.default_rng(0)
@@ -108,5 +113,5 @@ def test_shard_map_moe_matches_gspmd_on_8_devices():
     process is locked to 1."""
     r = subprocess.run([sys.executable, "-c", SHARD_MAP_SCRIPT],
                        capture_output=True, text=True, timeout=600,
-                       cwd=".")
+                       cwd=".", env=CPU_ENV)
     assert "SHARD_MAP_OK" in r.stdout, r.stdout + r.stderr

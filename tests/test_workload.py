@@ -425,3 +425,21 @@ def test_timeline_reads_monotonic_across_leader_failover():
     # versions actually advanced across both failovers (writes resumed)
     assert versions[-1] > versions[0] + 100
     assert len(sched.applied) == 4
+
+
+def test_compile_cache_dir_is_fixed_unless_set(monkeypatch, tmp_path):
+    import jax
+
+    from repro.compile_cache import REPO_ROOT, use_compile_cache
+    assert (REPO_ROOT / "src" / "repro" / "compile_cache.py").is_file()
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert use_compile_cache() == REPO_ROOT / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == \
+            str(REPO_ROOT / ".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
