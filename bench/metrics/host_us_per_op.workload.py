@@ -1,0 +1,10 @@
+"""Host microseconds per completed op spent in the workload layer
+(workload/*, the sampler's host side included): the layer's share
+of the stack samples, times the traced window's wall, over the ops
+completed in it."""
+
+from bench.stacks import us_per_op
+
+
+def read(obs):
+    return us_per_op(obs, "workload")
