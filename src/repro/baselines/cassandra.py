@@ -24,8 +24,8 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 from ..core.cluster import key_of
-from ..core.sim import (Disk, DiskParams, FifoServer, LatencyStats, NetParams,
-                        Network, Simulator)
+from ..core.sim import (Disk, DiskParams, FifoServer, NetParams, Network,
+                        Simulator)
 from ..core.types import ErrorCode, Result
 from ..obs import Observability, ObsConfig
 
@@ -457,8 +457,6 @@ class CassandraClient:
         self.cluster = cluster
         self.sim = cluster.sim
         self.id = client_id
-        self.stats = LatencyStats()
-        self.stats_by_kind: dict[str, LatencyStats] = {}
         self.op_hook: Optional[Callable[[str, Result], None]] = None
         self._rr = 0
         # workload adapters set this right before issuing an op so traces
@@ -510,9 +508,6 @@ class CassandraClient:
             settled[0] = True
             timeout_ev.cancel()
             res.latency = self.sim.now - t0
-            self.stats.add(res.latency)
-            self.stats_by_kind.setdefault(path, LatencyStats()).add(
-                res.latency)
             tr = kw.pop("_trace", None)
             if tr is not None:
                 self.cluster.obs.tracer.finish(

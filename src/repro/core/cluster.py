@@ -19,9 +19,10 @@ from . import ranges as ranges_mod
 from .coordination import Coordination, NoNode
 from .node import NodeConfig, SpinnakerNode
 from .ranges import BalancerConfig, RangeBalancer, RangeTable
-from .sim import LatencyStats, NetParams, Network, Simulator
+from .sim import NetParams, Network, Simulator
 from .types import ErrorCode, KeyRange, OpType, Result, WriteOp
 from ..obs import Observability, ObsConfig, install_node_gauges
+from ..obs.hostprof import CLIENT, WORKLOAD
 
 
 @dataclass
@@ -306,8 +307,6 @@ class Client:
         self.txn2_issued = 0         # cross-range (2PC) transaction sends
         self.lock_retries = 0        # LOCKED replies (no-wait lock policy)
         self._rr = 0
-        self.stats = LatencyStats()
-        self.stats_by_kind: dict[str, LatencyStats] = {}
         self.errors = 0
         self._session_seen: dict[tuple[str, str], int] = {}
         # client-perceived robustness counters (chaos runs report these as
@@ -455,40 +454,35 @@ class Client:
                     self._session_seen[_key] = max(seen, res.version)
                 inner(res)
 
-        self._op("read", key, dict(key=key, colname=colname,
-                                   consistent=consistent), cb,
-                 consistent=consistent, t0=self.sim.now, tries=0)
+        self._start("read", key, dict(key=key, colname=colname,
+                                      consistent=consistent), cb,
+                    consistent)
 
     def put(self, key: str, colname: str, value: Any,
             cb: Callable[[Result], None]) -> None:
         op = WriteOp(OpType.PUT, key, colname, value)
-        self._op("write", key, dict(op=op), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("write", key, dict(op=op), cb, True)
 
     def delete(self, key: str, colname: str, cb: Callable) -> None:
         op = WriteOp(OpType.DELETE, key, colname)
-        self._op("write", key, dict(op=op), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("write", key, dict(op=op), cb, True)
 
     def conditional_put(self, key: str, colname: str, value: Any, version: int,
                         cb: Callable) -> None:
         op = WriteOp(OpType.COND_PUT, key, colname, value,
                      expected_version=version)
-        self._op("write", key, dict(op=op), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("write", key, dict(op=op), cb, True)
 
     def conditional_delete(self, key: str, colname: str, version: int,
                            cb: Callable) -> None:
         op = WriteOp(OpType.COND_DELETE, key, colname,
                      expected_version=version)
-        self._op("write", key, dict(op=op), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("write", key, dict(op=op), cb, True)
 
     def multi_put(self, key: str, columns: list[tuple[str, Any]],
                   cb: Callable) -> None:
         op = WriteOp(OpType.MULTI_PUT, key, columns=tuple(columns))
-        self._op("write", key, dict(op=op), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("write", key, dict(op=op), cb, True)
 
     def multi_get(self, pairs: list[tuple[str, str]], consistent: bool,
                   cb: Callable[[list[Result]], None],
@@ -503,26 +497,27 @@ class Client:
         if not pairs:
             cb([])
             return
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(CLIENT)
         results: list[Optional[Result]] = [None] * len(pairs)
         pending = [len(pairs)]
         t0 = self.sim.now
 
         def settle(i: int, res: Result, record: bool) -> None:
+            hp = self.sim.hostprof
+            if hp is not None:
+                hp.enter(WORKLOAD)
             if record:
                 res.latency = self.sim.now - t0
-                if res.code != ErrorCode.TIMEOUT:
-                    # retry-exhausted timeouts are reported (op_hook,
-                    # errors) but kept out of the latency population,
-                    # matching the single-op path
-                    self.stats.add(res.latency)
-                    self.stats_by_kind.setdefault(
-                        "read", LatencyStats()).add(res.latency)
                 if self.op_hook is not None:
                     self.op_hook("read", res)
             results[i] = res
             pending[0] -= 1
             if pending[0] == 0:
                 cb(results)  # type: ignore[arg-type]
+            if hp is not None:
+                hp.leave()
 
         def deliver(i: int, res: Result) -> None:
             key, colname = pairs[i]
@@ -531,7 +526,7 @@ class Client:
                 seen = self._session_seen.get((key, colname), -1)
                 if res.version < seen:
                     # stale replica: fall back to the single-get retry path
-                    # (it records its own stats)
+                    # (it reports to op_hook itself)
                     self.get(key, colname, False,
                              lambda r, _i=i: settle(_i, r, False),
                              monotonic=True)
@@ -541,6 +536,8 @@ class Client:
 
         self._mread([(i, k, c) for i, (k, c) in enumerate(pairs)],
                     consistent, deliver, tries=0)
+        if hp is not None:
+            hp.leave()
 
     # per-key retryable mread results (reads never bounce on locks —
     # strong reads of locked keys defer server-side instead)
@@ -647,10 +644,33 @@ class Client:
         if not ops:
             cb(Result(ErrorCode.OK))
             return
-        self._op("txn", ops[0].key, dict(ops=ops), cb, consistent=True,
-                 t0=self.sim.now, tries=0)
+        self._start("txn", ops[0].key, dict(ops=ops), cb, True)
 
     # -- engine --------------------------------------------------------------------
+    def _start(self, kind: str, key: str, kw: dict, cb: Callable,
+               consistent: bool) -> None:
+        """A caller's op enters the client library: its first attempt,
+        under the host profile's `client` layer."""
+        hp = self.sim.hostprof
+        if hp is None:
+            self._op(kind, key, kw, cb, consistent, self.sim.now, 0)
+            return
+        hp.enter(CLIENT)
+        self._op(kind, key, kw, cb, consistent, self.sim.now, 0)
+        hp.leave()
+
+    def _finish(self, kind: str, res: Result, cb: Callable) -> None:
+        """Hand a finished op back to its caller (`op_hook`, then `cb`),
+        under the host profile's `workload` layer."""
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(WORKLOAD)
+        if self.op_hook is not None:
+            self.op_hook(kind, res)
+        cb(res)
+        if hp is not None:
+            hp.leave()
+
     def _op(self, kind: str, key: str, kw: dict, cb: Callable,
             consistent: bool, t0: float, tries: int) -> None:
         if tries == 0:
@@ -670,9 +690,7 @@ class Client:
                 self.cluster.obs.tracer.finish(tr, False, "timeout")
             res = Result(ErrorCode.TIMEOUT, latency=self.sim.now - t0,
                          attempts=tries)
-            if self.op_hook is not None:
-                self.op_hook(kind, res)
-            cb(res)
+            self._finish(kind, res, cb)
             return
         rid = self.range_table.lookup(key)
         wire_kind, payload_kw = kind, kw
@@ -737,12 +755,7 @@ class Client:
             if tr is not None:
                 self.cluster.obs.tracer.finish(
                     tr, res.ok, getattr(res.code, "name", str(res.code)))
-            self.stats.add(res.latency)
-            self.stats_by_kind.setdefault(kind, LatencyStats()).add(
-                res.latency)
-            if self.op_hook is not None:
-                self.op_hook(kind, res)
-            cb(res)
+            self._finish(kind, res, cb)
 
         def on_timeout():
             if settled[0]:

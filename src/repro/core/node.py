@@ -8,6 +8,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Optional, TYPE_CHECKING
 
+from ..obs.hostprof import PROTOCOL
 from . import ranges as ranges_mod
 from .replica import CohortReplica, ReplicaConfig, Role
 from .sim import Disk, DiskParams, FifoServer
@@ -408,7 +409,11 @@ class SpinnakerNode:
         if not isinstance(records, list):
             records = kw.get("ops")
         n = len(records) if isinstance(records, list) else 1
-        self._dispatch(handler, component_of(handler), base, per_rec * n,
+        comp = component_of(handler)
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.message(comp)
+        self._dispatch(handler, comp, base, per_rec * n,
                        lambda: getattr(replica, handler)(**kw), rid)
 
     # -- ingress batching (see NodeConfig.ingress_batch) -----------------------
@@ -472,6 +477,10 @@ class SpinnakerNode:
                 # the draining flag makes replica proposal accumulators
                 # hold their flush until every staged write has been
                 # admitted, so one ingress batch feeds one proposal batch
+                # the thunks and the drain hooks run the replicas' code
+                hp = self.sim.hostprof
+                if hp is not None:
+                    hp.enter(PROTOCOL)
                 self.ingress_draining = True
                 try:
                     for _k, _c, _b, _m, thunk, _r in job:
@@ -480,6 +489,8 @@ class SpinnakerNode:
                     self.ingress_draining = False
                 for rep in self.replicas.values():
                     rep.on_ingress_drained()
+                if hp is not None:
+                    hp.leave()
 
             self.cpu.submit(total, run_batch)
 
@@ -506,8 +517,12 @@ class SpinnakerNode:
             return
 
         def deliver(items=batch):
+            hp = self.sim.hostprof
             for cb, res, _nb in items:
-                cb(res)
+                if hp is None:
+                    cb(res)
+                else:
+                    hp.callback(cb, (res,))
 
         self.net.send(self.node_id, client_id, deliver,
                       nbytes=sum(nb for _cb, _res, nb in batch),

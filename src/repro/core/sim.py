@@ -15,6 +15,8 @@ import random
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+from ..obs.hostprof import NET, QUEUES, SCHED
+
 
 class Event:
     """A cancellable scheduled callback."""
@@ -44,13 +46,23 @@ class Simulator:
         self._seq = itertools.count()
         self.rng = random.Random(seed)
         self.events_processed = 0
+        # wall-clock host profile (obs/hostprof.py), None when off
+        self.hostprof = None
+        # events scheduled by the running handler, inside a profiled run
+        self._staged: Optional[list[Event]] = None
 
     # -- scheduling ---------------------------------------------------------
     def schedule(self, delay: float, fn: Callable, *args: Any) -> Event:
         if delay < 0:
             raise ValueError(f"negative delay {delay}")
         ev = Event(self.now + delay, next(self._seq), fn, args)
-        heapq.heappush(self._heap, ev)
+        staged = self._staged
+        if staged is None:
+            heapq.heappush(self._heap, ev)
+        else:
+            # a profiled run pushes it once the handler returns, under
+            # `sched`; (time, seq) orders the heap the same either way
+            staged.append(ev)
         return ev
 
     def at(self, time: float, fn: Callable, *args: Any) -> Event:
@@ -73,6 +85,9 @@ class Simulator:
 
     def run(self, until: Optional[float] = None, max_events: int = 50_000_000) -> None:
         """Run events until the queue empties or the clock passes `until`."""
+        if self.hostprof is not None:
+            self._run_profiled(until, max_events)
+            return
         n = 0
         while self._heap:
             ev = self._heap[0]
@@ -89,6 +104,59 @@ class Simulator:
                 raise RuntimeError(f"simulation exceeded {max_events} events")
         if until is not None:
             self.now = max(self.now, until)
+
+    def _run_profiled(self, until: Optional[float], max_events: int) -> None:
+        """`run` under the host profile: the loop, pops, cancelled skips
+        and the pushes of the events each handler schedules are charged to
+        `sched`, each event to its handler's layer."""
+        hp = self.hostprof
+        heap = self._heap
+        push, pop = heapq.heappush, heapq.heappop
+        self._staged = staged = []
+        pops = cancelled = depth_sum = 0
+        depth_max = hp.depth_max
+        hp.enter(SCHED)
+        try:
+            n = 0
+            while heap:
+                ev = heap[0]
+                if not ev.cancelled and until is not None \
+                        and ev.time > until:
+                    self.now = until
+                    return
+                depth = len(heap)
+                pop(heap)
+                pops += 1
+                depth_sum += depth
+                if depth > depth_max:
+                    depth_max = depth
+                if ev.cancelled:
+                    cancelled += 1
+                    continue
+                if ev.time < self.now - 1e-12:
+                    raise RuntimeError("event scheduled in the past")
+                self.now = max(self.now, ev.time)
+                self.events_processed += 1
+                hp.dispatch(ev.fn, ev.args)
+                if staged:
+                    for e in staged:
+                        push(heap, e)
+                    staged.clear()
+                n += 1
+                if n > max_events:
+                    raise RuntimeError(
+                        f"simulation exceeded {max_events} events")
+            if until is not None:
+                self.now = max(self.now, until)
+        finally:
+            for e in staged:
+                push(heap, e)
+            self._staged = None
+            hp.pops += pops
+            hp.cancelled_pops += cancelled
+            hp.depth_sum += depth_sum
+            hp.depth_max = depth_max
+            hp.leave()
 
     def run_until_idle(self, max_events: int = 50_000_000) -> None:
         self.run(until=None, max_events=max_events)
@@ -125,7 +193,6 @@ class FifoServer:
         self.sim = sim
         self.name = name
         self.busy_until: float = 0.0
-        self.queue_len = 0
         self.total_busy = 0.0
         self.jobs = 0
         self._open = True
@@ -134,7 +201,6 @@ class FifoServer:
     def reset(self) -> None:
         """Drop queued work (e.g. on node crash)."""
         self.busy_until = self.sim.now
-        self.queue_len = 0
 
     def close(self) -> None:
         self._open = False
@@ -149,6 +215,9 @@ class FifoServer:
         """Enqueue a job; returns its completion time."""
         if not self._open:
             return float("inf")
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(QUEUES)
         service_time *= self.slow_factor
         start = max(self.sim.now, self.busy_until)
         done = start + service_time
@@ -161,6 +230,8 @@ class FifoServer:
                 if self._open and self._gen == gen:
                     cb(*args)
             self.sim.schedule(done - self.sim.now, fire)
+        if hp is not None:
+            hp.leave()
         return done
 
     _gen = 0
@@ -170,8 +241,7 @@ class FifoServer:
 
     def queue_delay(self) -> float:
         """Seconds of already-accepted work ahead of a job submitted now —
-        the queue-depth gauge the metrics registry scrapes (the header
-        `queue_len` counter is not maintained by `submit`)."""
+        the queue-depth gauge the metrics registry scrapes."""
         return max(0.0, self.busy_until - self.sim.now)
 
 
@@ -313,8 +383,13 @@ class Network:
     def send(self, src: Any, dst: Any, handler: Callable, *args: Any,
              nbytes: int = 256, cross_switch: bool = False,
              component: Optional[str] = None, rid: Any = None) -> None:
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(NET)
         if self._blocked(src, dst):
             self.dropped += 1
+            if hp is not None:
+                hp.leave()
             return  # dropped
         fault = self._link_faults.get((src, dst))
         copies = 1
@@ -323,6 +398,8 @@ class Network:
             drop_p, dup_p, delay_factor = fault
             if drop_p and self.sim.rng.random() < drop_p:
                 self.dropped += 1
+                if hp is not None:
+                    hp.leave()
                 return  # silently eaten by the flaky link
             if dup_p and self.sim.rng.random() < dup_p:
                 copies = 2
@@ -362,6 +439,8 @@ class Network:
                 handler(*args)
 
             self.sim.at(deliver_at, deliver)
+        if hp is not None:
+            hp.leave()
 
 
 @dataclass
@@ -426,9 +505,14 @@ class Disk:
         Requests arriving while the head is busy are coalesced into one
         batch force when the head frees up — this IS group commit [13].
         """
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(QUEUES)
         self._waiters.append((nbytes, cb, component, rid))
         if not self.busy:
             self._start_batch()
+        if hp is not None:
+            hp.leave()
 
     def _start_batch(self) -> None:
         if not self._waiters:
@@ -458,8 +542,12 @@ class Disk:
             if gen != self._gen:
                 return
             self.busy = False
+            hp = self.sim.hostprof
             for b in batch:
-                b[1]()
+                if hp is None:
+                    b[1]()
+                else:
+                    hp.callback(b[1], ())
             self._start_batch()
 
         self.sim.schedule(lat, done)

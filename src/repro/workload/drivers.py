@@ -335,6 +335,7 @@ class ClosedLoopDriver:
         self.sim = sim
         self.adapter = adapter
         self.stream = stream
+        stream.sim = sim            # its refills reach the host profile
         self.log = log
         self.n_clients = n_clients
         self.think_time = think_time
@@ -384,6 +385,7 @@ class OpenLoopDriver:
         self.sim = sim
         self.adapter = adapter
         self.stream = stream
+        stream.sim = sim            # its refills reach the host profile
         self.log = log
         self.rate = rate
         self.max_outstanding = max_outstanding

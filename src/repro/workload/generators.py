@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import enum
 import math
+import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Iterator, Optional
@@ -24,6 +25,8 @@ from typing import Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs.hostprof import BATCH_SPAN, SAMPLER_WAIT
 
 
 class OpKind(enum.IntEnum):
@@ -93,21 +96,27 @@ def _zipf_cdf(n: int, theta: float) -> jnp.ndarray:
 def _sample_batch(key, cdf: Optional[jnp.ndarray], mix_cdf: jnp.ndarray,
                   num_keys: int, vfix: int, vmin: int, vmax: int,
                   batch: int):
-    """One fused sampling step: (key ranks, op kinds, value sizes, gaps)."""
+    """One fused sampling step: (key ranks, op kinds, value sizes, gaps).
+    Each draw sits under a named scope, which names its device ops in a
+    profiler trace."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    u = jax.random.uniform(k1, (batch,))
-    if cdf is None:                       # uniform keys
-        ranks = jnp.floor(u * num_keys).astype(jnp.int32)
-    else:                                 # zipfian CDF inversion
-        ranks = jnp.searchsorted(cdf, u).astype(jnp.int32)
-    ranks = jnp.clip(ranks, 0, num_keys - 1)
-    ops = jnp.searchsorted(mix_cdf, jax.random.uniform(k2, (batch,)))
-    if vmax > vmin:
-        vsz = jax.random.randint(k3, (batch,), vmin, vmax + 1)
-    else:
-        vsz = jnp.full((batch,), vfix, jnp.int32)
-    # unit-rate exponential gaps; the driver scales by 1/rate
-    gaps = -jnp.log1p(-jax.random.uniform(k4, (batch,)))
+    with jax.named_scope("zipf_search"):
+        u = jax.random.uniform(k1, (batch,))
+        if cdf is None:                       # uniform keys
+            ranks = jnp.floor(u * num_keys).astype(jnp.int32)
+        else:                                 # zipfian CDF inversion
+            ranks = jnp.searchsorted(cdf, u).astype(jnp.int32)
+        ranks = jnp.clip(ranks, 0, num_keys - 1)
+    with jax.named_scope("op_mix"):
+        ops = jnp.searchsorted(mix_cdf, jax.random.uniform(k2, (batch,)))
+    with jax.named_scope("value_size"):
+        if vmax > vmin:
+            vsz = jax.random.randint(k3, (batch,), vmin, vmax + 1)
+        else:
+            vsz = jnp.full((batch,), vfix, jnp.int32)
+    with jax.named_scope("gaps"):
+        # unit-rate exponential gaps; the driver scales by 1/rate
+        gaps = -jnp.log1p(-jax.random.uniform(k4, (batch,)))
     return ranks, ops.astype(jnp.int32), vsz.astype(jnp.int32), \
         gaps.astype(jnp.float32)
 
@@ -118,6 +127,12 @@ class OpStream:
     `next_op()` costs an array read; a new jitted batch is drawn every
     `batch` ops.  Streams with the same (spec, seed) are identical, which
     makes every benchmark bit-reproducible.
+
+    A driver that draws from the stream sets `sim` to its simulator; while
+    that simulator carries a host profile, each refill is timed as
+    `sampler_wait` and opens the profile's per-batch trace annotation.
+    `first_refill_s` is the wall time of the first refill: tracing,
+    compiling or loading the sampler, and its first run.
     """
 
     def __init__(self, spec: WorkloadSpec, seed: int = 0, batch: int = 8192):
@@ -143,27 +158,40 @@ class OpStream:
         self._i = self.batch          # force refill on first use
         self._keys = self._ops = self._vsz = self._gaps = None
         self.sampled = 0
+        self.sim = None
+        self.first_refill_s: Optional[float] = None
         # `latest` support: the most recently inserted key index; drivers
         # bump this on successful writes
         self.insert_horizon = spec.num_keys
 
     def _refill(self) -> None:
-        self._key, sub = jax.random.split(self._key)
-        keys, ops, vsz, gaps = _sample_batch(
-            sub, self._cdf, self._mix_cdf, self.spec.num_keys,
-            self.spec.value_size, self._vmin, self._vmax, self.batch)
-        keys = np.asarray(keys)
+        hp = self.sim.hostprof if self.sim is not None else None
+        t0 = time.perf_counter() if self.first_refill_s is None else None
+        if hp is None:
+            keys, ops, vsz, gaps = self._draw()
+        else:
+            with jax.profiler.TraceAnnotation(BATCH_SPAN, **hp.batch_meta()):
+                hp.enter(SAMPLER_WAIT)
+                keys, ops, vsz, gaps = self._draw()
+                hp.leave()
         if self._mult > 1:
             # bijective scramble rank -> key in int64 on the host (the
             # product overflows int32 for large keyspaces under jit)
             keys = ((keys.astype(np.int64) * self._mult + self._offset)
                     % self.spec.num_keys).astype(np.int32)
-        self._keys = keys
-        self._ops = np.asarray(ops)
-        self._vsz = np.asarray(vsz)
-        self._gaps = np.asarray(gaps)
+        self._keys, self._ops, self._vsz, self._gaps = keys, ops, vsz, gaps
         self._i = 0
         self.sampled += self.batch
+        if t0 is not None:
+            self.first_refill_s = time.perf_counter() - t0
+
+    def _draw(self) -> tuple:
+        """One batch from the sampler, as numpy arrays on the host."""
+        self._key, sub = jax.random.split(self._key)
+        out = _sample_batch(
+            sub, self._cdf, self._mix_cdf, self.spec.num_keys,
+            self.spec.value_size, self._vmin, self._vmax, self.batch)
+        return tuple(np.asarray(x) for x in out)
 
     def _key_index(self, rank: int) -> int:
         if self.spec.key_dist == "latest":
