@@ -3,8 +3,10 @@
 Every component of the Spinnaker reproduction (nodes, disks, network,
 coordination service, clients) runs on this simulator so that arbitrary
 failure schedules are reproducible bit-for-bit from a seed.  Time is in
-seconds (float).  Events with equal timestamps are ordered by insertion
-sequence, which makes runs deterministic regardless of heap tie-breaking.
+seconds (float).  The pending events form one binary heap of `Event`
+entries `[time, seq, fn, args]`, ordered by `time` and then by the
+insertion sequence `seq`: events with equal timestamps run FIFO, which
+makes runs deterministic regardless of heap tie-breaking.
 """
 
 from __future__ import annotations
@@ -18,23 +20,29 @@ from typing import Any, Callable, Optional
 from ..obs.hostprof import NET, QUEUES, SCHED
 
 
-class Event:
-    """A cancellable scheduled callback."""
+class Event(list):
+    """A cancellable scheduled callback: the heap entry `[time, seq, fn,
+    args]` itself.
 
-    __slots__ = ("time", "seq", "fn", "args", "cancelled")
+    It has no `__lt__`: `heapq` compares entries as lists, in C, by `time`
+    and then by `seq`, which is unique, so it never reaches `fn`.
+    `cancel()` sets `fn` to None and `args` to `()`, which frees the
+    callback's closure and arguments at once; the loops skip an entry whose
+    `fn` is None when it pops."""
 
-    def __init__(self, time: float, seq: int, fn: Callable, args: tuple):
-        self.time = time
-        self.seq = seq
-        self.fn = fn
-        self.args = args
-        self.cancelled = False
+    __slots__ = ()
+
+    @property
+    def time(self) -> float:
+        return self[0]
+
+    @property
+    def cancelled(self) -> bool:
+        return self[2] is None
 
     def cancel(self) -> None:
-        self.cancelled = True
-
-    def __lt__(self, other: "Event") -> bool:
-        return (self.time, self.seq) < (other.time, other.seq)
+        self[2] = None
+        self[3] = ()
 
 
 class Simulator:
@@ -55,7 +63,7 @@ class Simulator:
     def schedule(self, delay: float, fn: Callable, *args: Any) -> Event:
         if delay < 0:
             raise ValueError(f"negative delay {delay}")
-        ev = Event(self.now + delay, next(self._seq), fn, args)
+        ev = Event((self.now + delay, next(self._seq), fn, args))
         staged = self._staged
         if staged is None:
             heapq.heappush(self._heap, ev)
@@ -71,15 +79,16 @@ class Simulator:
     # -- execution ----------------------------------------------------------
     def step(self) -> bool:
         """Run one event.  Returns False when the queue is exhausted."""
-        while self._heap:
-            ev = heapq.heappop(self._heap)
-            if ev.cancelled:
+        heap = self._heap
+        while heap:
+            time, _, fn, args = heapq.heappop(heap)
+            if fn is None:
                 continue
-            if ev.time < self.now - 1e-12:
+            if time < self.now - 1e-12:
                 raise RuntimeError("event scheduled in the past")
-            self.now = max(self.now, ev.time)
+            self.now = max(self.now, time)
             self.events_processed += 1
-            ev.fn(*ev.args)
+            fn(*args)
             return True
         return False
 
@@ -88,17 +97,23 @@ class Simulator:
         if self.hostprof is not None:
             self._run_profiled(until, max_events)
             return
+        heap = self._heap
+        pop = heapq.heappop
         n = 0
-        while self._heap:
-            ev = self._heap[0]
-            if ev.cancelled:
-                heapq.heappop(self._heap)
+        while heap:
+            time, _, fn, args = heap[0]
+            if fn is None:
+                pop(heap)
                 continue
-            if until is not None and ev.time > until:
+            if until is not None and time > until:
                 self.now = until
                 return
-            if not self.step():
-                return
+            pop(heap)
+            if time < self.now - 1e-12:
+                raise RuntimeError("event scheduled in the past")
+            self.now = max(self.now, time)
+            self.events_processed += 1
+            fn(*args)
             n += 1
             if n > max_events:
                 raise RuntimeError(f"simulation exceeded {max_events} events")
@@ -119,9 +134,8 @@ class Simulator:
         try:
             n = 0
             while heap:
-                ev = heap[0]
-                if not ev.cancelled and until is not None \
-                        and ev.time > until:
+                time, _, fn, args = heap[0]
+                if fn is not None and until is not None and time > until:
                     self.now = until
                     return
                 depth = len(heap)
@@ -130,14 +144,14 @@ class Simulator:
                 depth_sum += depth
                 if depth > depth_max:
                     depth_max = depth
-                if ev.cancelled:
+                if fn is None:
                     cancelled += 1
                     continue
-                if ev.time < self.now - 1e-12:
+                if time < self.now - 1e-12:
                     raise RuntimeError("event scheduled in the past")
-                self.now = max(self.now, ev.time)
+                self.now = max(self.now, time)
                 self.events_processed += 1
-                hp.dispatch(ev.fn, ev.args)
+                hp.dispatch(fn, args)
                 if staged:
                     for e in staged:
                         push(heap, e)
