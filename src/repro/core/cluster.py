@@ -11,6 +11,7 @@ their own RangeTable cache and chase WRONG_RANGE redirects.
 from __future__ import annotations
 
 import bisect
+import itertools
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -22,7 +23,7 @@ from .ranges import BalancerConfig, RangeBalancer, RangeTable
 from .sim import NetParams, Network, Simulator
 from .types import ErrorCode, KeyRange, OpType, Result, WriteOp
 from ..obs import Observability, ObsConfig, install_node_gauges
-from ..obs.hostprof import CLIENT, WORKLOAD
+from ..obs.hostprof import CLIENT, RECOVERY, WORKLOAD
 
 
 @dataclass
@@ -282,7 +283,15 @@ class Client:
     metadata (`core/ranges.py`), invalidated by a data-change watch on the
     table version znode or by a WRONG_RANGE reply from a replica whose
     range no longer covers the key (live splits move keys between cohorts
-    mid-flight)."""
+    mid-flight).
+
+    Leader failover: the client watches the leader znode of every range
+    it routes to.  When the coordination service names another leader,
+    every attempt still waiting at the old one is sent again through the
+    retry path; a crashed leader sends no reply, so without the watch each
+    of them would wait out its attempt timeout, which stays as the
+    backstop where no leader change is seen (a partitioned leader whose
+    session lives)."""
 
     MAX_RETRIES = 60
     BACKOFF_BASE = 0.02      # first retry delay; doubles per retry ...
@@ -331,6 +340,16 @@ class Client:
         # request envelopes: per-target staging (see COALESCE_WINDOW)
         self._req_buf: dict[int, list[tuple]] = {}
         self.req_envelopes = 0       # multi-request envelopes sent
+        # leader failover: ranges whose leader znode is watched, the leader
+        # it last named, and per range the attempts waiting at a leader
+        # (attempt id -> (its abandon callback, the node it was sent to));
+        # keyed by id so that no attempt's closure refers to itself
+        self._leader_watched: set[int] = set()
+        self._named_leader: dict[int, int] = {}
+        self._waiting: dict[int, dict[int, tuple[Callable, int]]] = {}
+        self._attempt_ids = itertools.count()
+        self.failovers = 0           # leader changes followed
+        self.failover_resends = 0    # attempts sent again on them
 
     # -- routing -----------------------------------------------------------------
     def _retry_delay(self, tries: int) -> float:
@@ -421,12 +440,54 @@ class Client:
         cached = self.leader_cache.get(rid)
         if cached is not None:
             return cached
+        path = f"/ranges/{rid}/leader"
+        if rid not in self._leader_watched:
+            self._leader_watched.add(rid)
+            self.cluster.zk.watch_exists(path, self._on_leader_change)
         try:
-            leader_id, _epoch = self.cluster.zk.get(f"/ranges/{rid}/leader")
-            self.leader_cache[rid] = leader_id
-            return leader_id
+            leader_id, _epoch = self.cluster.zk.get(path)
         except NoNode:
             return None
+        self.leader_cache[rid] = self._named_leader[rid] = leader_id
+        return leader_id
+
+    def _on_leader_change(self, path: str) -> None:
+        """A range's leader znode changed.  Gone: forget the leader.
+        Naming another: route to it, and send again every attempt still
+        waiting at any other node, through the retry path."""
+        rid = int(path.split("/")[2])
+        zk = self.cluster.zk
+        zk.watch_exists(path, self._on_leader_change)    # watches are one-shot
+        try:
+            new, _epoch = zk.get(path)
+        except NoNode:
+            self.leader_cache.pop(rid, None)
+            return
+        old = self._named_leader.get(rid)
+        if new == old:
+            return
+        self.leader_cache[rid] = self._named_leader[rid] = new
+        hp = self.sim.hostprof
+        if hp is not None:
+            hp.enter(RECOVERY)
+        waiting = self._waiting.get(rid, {})
+        stale = [abandon for abandon, target in waiting.values()
+                 if target != new]
+        for abandon in stale:
+            abandon(True)
+        self.failovers += 1
+        self.failover_resends += len(stale)
+        self.cluster.obs.events.emit("client_failover", client=self.id,
+                                     rid=rid, old=old, new=new,
+                                     resent=len(stale))
+        if hp is not None:
+            hp.leave()
+
+    def _waiting_at(self, rid: int) -> dict[int, tuple[Callable, int]]:
+        waiting = self._waiting.get(rid)
+        if waiting is None:
+            waiting = self._waiting[rid] = {}
+        return waiting
 
     def _any_replica(self, rid: int) -> Optional[int]:
         members = self.range_table.members(rid)
@@ -581,6 +642,8 @@ class Client:
             return
         self.mread_batches += 1
         settled = [False]
+        waiting = self._waiting_at(rid) if consistent else None
+        aid = next(self._attempt_ids)
 
         def retry(residue: list, saw_wrong_range: bool,
                   leader_hint: Optional[int]) -> None:
@@ -598,6 +661,8 @@ class Client:
                 return
             settled[0] = True
             timeout_ev.cancel()
+            if waiting is not None:
+                del waiting[aid]
             if isinstance(res, Result):
                 self._note_reply(res)
             if res is None or isinstance(res, Result):
@@ -618,15 +683,26 @@ class Client:
             if redo:
                 retry(redo, wrong, None)
 
-        def on_timeout() -> None:
+        def on_timeout(failover: bool = False) -> None:
+            """The attempt timer fired, or (`failover`) the range's
+            leader changed while the attempt waited at the old one."""
             if settled[0]:
                 return
             settled[0] = True
+            if waiting is not None:
+                del waiting[aid]
+            if failover:
+                timeout_ev.cancel()
+                self.sim.schedule(self._retry_delay(tries), self._mread,
+                                  items, consistent, deliver, tries + 1)
+                return
             self._note_reply(None)
             retry(items, False, None)
 
         timeout_ev = self.sim.schedule(self._attempt_timeout(tries),
                                        on_timeout)
+        if waiting is not None:
+            waiting[aid] = (on_timeout, target)
         payload = dict(pairs=[(k, c) for _i, k, c in items],
                        consistent=consistent,
                        reply=self._reply_via_net(target, on_reply))
@@ -709,10 +785,13 @@ class Client:
                 wire_kind = "txn2"
                 payload_kw = dict(groups=groups)
                 self.txn2_issued += 1
+        waiting = None
         if kind == "read" and not consistent:
             target = self._any_replica(rid) if rid is not None else None
         else:
             target = self._lookup_leader(rid) if rid is not None else None
+            if target is not None:
+                waiting = self._waiting_at(rid)
         if target is None:
             if rid is None:
                 self.range_table.invalidate()
@@ -720,6 +799,7 @@ class Client:
             return
 
         settled = [False]
+        aid = next(self._attempt_ids)
 
         def retry(res: Optional[Result]):
             self.leader_cache.pop(rid, None)
@@ -738,6 +818,8 @@ class Client:
                 return
             settled[0] = True
             timeout_ev.cancel()
+            if waiting is not None:
+                del waiting[aid]
             self._note_reply(res)
             if res is not None and res.code == ErrorCode.LOCKED:
                 self.lock_retries += 1
@@ -757,15 +839,26 @@ class Client:
                     tr, res.ok, getattr(res.code, "name", str(res.code)))
             self._finish(kind, res, cb)
 
-        def on_timeout():
+        def on_timeout(failover: bool = False):
+            """The attempt timer fired, or (`failover`) the range's
+            leader changed while the attempt waited at the old one."""
             if settled[0]:
                 return
             settled[0] = True
+            if waiting is not None:
+                del waiting[aid]
+            if failover:
+                timeout_ev.cancel()
+                self._schedule_retry(kind, key, kw, cb, consistent, t0,
+                                     tries)
+                return
             self._note_reply(None)
             retry(None)
 
         timeout_ev = self.sim.schedule(self._attempt_timeout(tries),
                                        on_timeout)
+        if waiting is not None:
+            waiting[aid] = (on_timeout, target)
 
         payload = dict(payload_kw)
         payload.pop("_trace", None)

@@ -10,6 +10,7 @@ from typing import Any, Optional, TYPE_CHECKING
 
 from ..obs.hostprof import PROTOCOL
 from . import ranges as ranges_mod
+from .coordination import NodeExists
 from .replica import CohortReplica, ReplicaConfig, Role
 from .sim import Disk, DiskParams, FifoServer
 from .storage import Store
@@ -272,11 +273,7 @@ class SpinnakerNode:
         self.net.set_down(self.node_id, False)
         self.cpu.open()
         self.session = self.zk.create_session()
-        try:
-            self.zk.create(f"/nodes/{self.node_id}", data=self.sim.now,
-                           ephemeral_session=self.session)
-        except Exception:
-            pass
+        self._register(self.session)
         self._heartbeat()
         # reconcile hosted replicas with the registered range table first:
         # splits/member changes may have happened while this node was down
@@ -286,6 +283,29 @@ class SpinnakerNode:
         for replica in list(self.replicas.values()):
             if replica.role is Role.OFFLINE:
                 replica.start()
+
+    def _register(self, session: int) -> None:
+        """Create this node's `/nodes/<id>` znode under `session`.  After a
+        crash whose session did not expire, the previous life's session
+        still holds it: take it over once that session expires, and have
+        the followers re-announce themselves, since a leader drops a
+        follower from its in-sync set when the znode vanishes."""
+        path = f"/nodes/{self.node_id}"
+
+        def on_change(_path: str) -> None:
+            if session != self.session:
+                return                  # crashed or flapped since
+            if self.zk.exists(path):
+                self.zk.watch_exists(path, on_change)
+                return
+            self._register(session)
+            for rep in list(self.replicas.values()):
+                rep.announce_state()
+
+        try:
+            self.zk.create(path, data=self.sim.now, ephemeral_session=session)
+        except NodeExists:
+            self.zk.watch_exists(path, on_change)
 
     def _heartbeat(self) -> None:
         if not self.up:
@@ -349,6 +369,7 @@ class SpinnakerNode:
             self.wal.durable_bytes = 0
             self.wal.skipped.clear()
             self.wal.flushed_upto.clear()
+            self.wal.applied_upto.clear()
             self.wal._gc_dropped_upto.clear()
         if expire_session and self.session is not None:
             self.zk.expire_session(self.session)

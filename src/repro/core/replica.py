@@ -38,6 +38,7 @@ conditions ignored".
 from __future__ import annotations
 
 import enum
+import functools
 from dataclasses import dataclass
 from typing import Callable, Optional, TYPE_CHECKING
 
@@ -48,6 +49,7 @@ from .txn import TxnManager
 from .types import (CommitMarker, ErrorCode, KeyRange, LogRecord, OpType,
                     Result, TXN_OPS, WriteOp, fmt_lsn, lsn_epoch, lsn_seq,
                     make_lsn)
+from ..obs.hostprof import RECOVERY
 from ..obs.journal import record_digest
 
 if TYPE_CHECKING:
@@ -61,6 +63,21 @@ class Role(enum.Enum):
     FOLLOWER = "follower"
     TAKEOVER = "takeover"        # leader-elect running Fig. 6
     LEADER = "leader"
+
+
+def _recovery(handler: Callable) -> Callable:
+    """Run a recovery handler under the host profile's `recovery` layer."""
+    @functools.wraps(handler)
+    def run(self, *args, **kw):
+        hp = self.node.sim.hostprof
+        if hp is None:
+            return handler(self, *args, **kw)
+        hp.enter(RECOVERY)
+        try:
+            return handler(self, *args, **kw)
+        finally:
+            hp.leave()
+    return run
 
 
 @dataclass
@@ -199,6 +216,8 @@ class CohortReplica:
         # observability: sampled traces of admitted-but-uncommitted writes,
         # keyed by LSN (leader side only; never serialized into records)
         self._trace_by_lsn: dict[int, object] = {}
+        # leader: records sent to each catching-up follower since it asked
+        self._catchup_sent: dict[int, int] = {}
 
     # ------------------------------------------------------------------ utils
     @property
@@ -247,6 +266,7 @@ class CohortReplica:
             jr.record(kind, node=self.node.node_id, rid=self.rid, **fields)
 
     # ============================================================== lifecycle
+    @_recovery
     def start(self) -> None:
         """Called after the node's local recovery pass for this range."""
         records, cmt = self.node.wal.recover_range(self.rid)
@@ -257,9 +277,13 @@ class CohortReplica:
                        self.node.wal.flushed_upto.get(self.rid, 0))
         self.cmt = min(cmt, self.lst)
         # local recovery: re-apply (flushed, f.cmt] idempotently (§6.1)
+        replayed = 0
         for r in records:
             if self.store.flushed_upto < r.lsn <= self.cmt:
                 self.store.apply(r)
+                replayed += 1
+        self.obs.events.emit("local_recovery", node=self.node.node_id,
+                             rid=self.rid, records=replayed)
         # rebuild 2PC state (prepared txns + locks, logged decisions) from
         # the same scan — a leader promoted after this restart inherits
         # them from the log, not from anyone's memory
@@ -270,6 +294,7 @@ class CohortReplica:
         self.store.restrict(self.range.lo, self.range.hi)
         self.queue = {r.lsn: r for r in records if r.lsn > self.cmt}
         self._follower_forced = self.lst   # durable log scanned
+        self._commit_heard = self.cmt
         self._reset_batch()
         self.pending_reply.clear()
         self._trace_by_lsn.clear()
@@ -490,6 +515,7 @@ class CohortReplica:
         self.zk.watch_exists(leader_path, on_change)
 
     # ===================================================== leader takeover
+    @_recovery
     def _start_takeover(self, new_epoch: int) -> None:
         """Fig. 6.  We hold the leader znode; re-commit the unresolved
         window, then open for writes under `new_epoch`."""
@@ -667,6 +693,7 @@ class CohortReplica:
         copy, so re-proposals of it must be re-forced before being acked."""
         self.queue = {l: r for l, r in self.queue.items() if l <= self.cmt}
         self._follower_forced = min(self._follower_forced, self.cmt)
+        self._commit_heard = self.cmt
         self._trace_by_lsn.clear()   # dropped writes retry with fresh marks
         for lsn in list(self.pending_reply):
             cb = self.pending_reply.pop(lsn)
@@ -924,14 +951,20 @@ class CohortReplica:
             self._abdicate("zk session flapped", suppress=False)
         elif self.role in (Role.FOLLOWER, Role.CATCHUP) \
                 and self.leader_id is not None:
-            # re-announce so the leader re-syncs us (it zeroed our ack state
-            # when /nodes/<id> disappeared)
-            self._leader_seen = self.node.sim.now
-            self._send(self.leader_id, "on_follower_state", epoch=self.epoch,
-                       follower=self.node.node_id, f_cmt=self.cmt,
-                       f_lst=self.lst)
+            self.announce_state()
         else:
             self._join_or_elect()
+
+    def announce_state(self) -> None:
+        """Follower: re-announce to the leader so it re-syncs us (it zeroed
+        our ack state when /nodes/<id> disappeared)."""
+        if self.role not in (Role.FOLLOWER, Role.CATCHUP) \
+                or self.leader_id is None:
+            return
+        self._leader_seen = self.node.sim.now
+        self._send(self.leader_id, "on_follower_state", epoch=self.epoch,
+                   follower=self.node.node_id, f_cmt=self.cmt,
+                   f_lst=self.lst)
 
     # --- read-index fallback (quorum-confirmed strong reads) ----------------
     def _fail_read_confirms(self) -> None:
@@ -986,6 +1019,7 @@ class CohortReplica:
                 thunk(True)
 
     # --- leader side: follower catch-up (§6.1 + Fig. 6 lines 3-8) ------------
+    @_recovery
     def on_follower_state(self, epoch: int, follower: int, f_cmt: int,
                           f_lst: int) -> None:
         if self.role not in (Role.LEADER, Role.TAKEOVER) or epoch != self.epoch:
@@ -998,6 +1032,7 @@ class CohortReplica:
         # a restarted follower must re-sync from scratch
         self.insync.discard(follower)
         self.acked[follower] = 0
+        self._catchup_sent[follower] = 0
         self.log(f"catch-up request from n{follower} "
                  f"(f.cmt={fmt_lsn(f_cmt)} f.lst={fmt_lsn(f_lst)})")
         self._send_catchup(follower, f_cmt, f_lst, first=True)
@@ -1019,11 +1054,14 @@ class CohortReplica:
             recs.extend(self.txn.catchup_extras(target))
             recs.sort(key=lambda r: r.lsn)
         nbytes = 128 + sum(r.nbytes() for r in recs)
+        self._catchup_sent[follower] = \
+            self._catchup_sent.get(follower, 0) + len(recs)
         self._send(follower, "on_catchup_data", nbytes=nbytes,
                    epoch=self.epoch, records=recs, commit_lsn=target,
                    truncate_from=f_cmt if first else None,
                    truncate_to=f_lst if first else None)
 
+    @_recovery
     def on_catchup_synced(self, epoch: int, follower: int, upto: int) -> None:
         if self.role not in (Role.LEADER, Role.TAKEOVER) or epoch != self.epoch:
             return
@@ -1033,6 +1071,11 @@ class CohortReplica:
             # is subsumed by the gap-forwarding below once upto == cmt)
             self._send_catchup(follower, upto, upto)
             return
+        if follower not in self.insync:
+            self.obs.events.emit(
+                "follower_caught_up", node=follower, rid=self.rid,
+                leader=self.node.node_id,
+                records=self._catchup_sent.pop(follower, 0))
         self.insync.add(follower)
         self.acked[follower] = max(self.acked.get(follower, 0), upto)
         # close the in-flight gap: forward pending proposals this follower
@@ -1095,6 +1138,7 @@ class CohortReplica:
                 self.client_write(op, cb, trace=tr)
 
     # --- follower side: catch-up data -----------------------------------------
+    @_recovery
     def on_catchup_data(self, epoch: int, records: list[LogRecord],
                         commit_lsn: int, truncate_from: Optional[int],
                         truncate_to: Optional[int]) -> None:
@@ -1460,15 +1504,12 @@ class CohortReplica:
             # nothing new to force: re-ack the watermark
             self._ack(max(self._follower_forced, self.cmt))
         if commit_lsn is not None:
-            before = self.cmt
-            self._apply_committed(min(commit_lsn, self.lst))
-            if self.cmt > before:
-                # piggybacked commit progress: persist the marker exactly
-                # as a dedicated on_commit broadcast would have
-                self.node.wal.append(CommitMarker(self.rid, self.cmt),
-                                     force=False)
+            # piggybacked commit progress: handled exactly as a dedicated
+            # on_commit broadcast would have been
+            self._follower_commit(commit_lsn)
 
     _follower_forced = 0
+    _commit_heard = 0          # follower: highest commit LSN the leader sent
     _dropped_catchup = False   # drop_first_catchup fault-hook latch
 
     def _on_follower_forced(self, lsn: int, epoch: int) -> None:
@@ -1485,6 +1526,21 @@ class CohortReplica:
         # watermark is the highest *contiguous* durable LSN: ack it once
         # for the whole batch instead of once per record
         self._ack(self._follower_forced)
+        if self._commit_heard > self.cmt and self.role is Role.FOLLOWER:
+            self._follower_commit(self._commit_heard)
+
+    def _follower_commit(self, commit_lsn: int) -> None:
+        """Apply what the leader reports committed, as far as this
+        replica's own log holds it durably: what it applies, local recovery
+        can replay after a crash (the rest follows when its force lands).
+        Progress is persisted with a commit marker."""
+        self._commit_heard = max(self._commit_heard, commit_lsn)
+        before = self.cmt
+        self._apply_committed(min(self._commit_heard, self._follower_forced,
+                                  self.lst))
+        if self.cmt > before:
+            self.node.wal.append(CommitMarker(self.rid, self.cmt),
+                                 force=False)
 
     def _ack(self, lsn: int) -> None:
         if self.role is not Role.FOLLOWER:
@@ -1572,6 +1628,7 @@ class CohortReplica:
                 ver = rec.columns[0][2] if rec.columns else None
                 cb(Result(ErrorCode.OK, version=ver))
         self.cmt = upto
+        self.node.wal.note_applied(self.rid, upto)
         self._jrec("commit_idx", epoch=self.epoch, lsn=upto)
         flushed = self.store.maybe_flush(self.cmt)
         if flushed is not None:
@@ -1844,19 +1901,19 @@ class CohortReplica:
         if self.role is not Role.FOLLOWER or epoch != self.epoch:
             return
         self._leader_seen = self.node.sim.now
-        before = self.cmt
-        self._apply_committed(min(commit_lsn, self.lst))
-        if self.cmt > before:
-            # persist only actual progress; a duplicate broadcast must not
-            # re-append an identical marker
-            self.node.wal.append(CommitMarker(self.rid, self.cmt), force=False)
+        # persists only actual progress; a duplicate broadcast must not
+        # re-append an identical marker
+        self._follower_commit(commit_lsn)
 
     # ===================================================== reads (§3, §5)
     def _read_gate(self, consistent: bool) -> Optional[Result]:
         """Role/session gate shared by single and batched reads."""
         if consistent:
-            # strong reads are served only by a live leader (§5)
-            if self.role is not Role.LEADER or not self.node.has_session():
+            # strong reads are served only by a live leader (§5) that has
+            # committed the window it took over: until then its store may
+            # lack writes the previous regime acknowledged
+            if self.role is not Role.LEADER or not self.open_for_writes \
+                    or not self.node.has_session():
                 return Result(ErrorCode.NOT_LEADER,
                               leader_hint=self.leader_id)
         else:

@@ -47,6 +47,12 @@ class WAL:
         self.skipped: dict[int, set[int]] = {}
         # per-range flushed-to-SSTable watermark (enables segment GC)
         self.flushed_upto: dict[int, int] = {}
+        # per-range commit point the replica has applied to its store, kept
+        # with the range's durable metadata beside the two above: local
+        # recovery replays to it, so a restarted replica never shows an
+        # older version than it had applied (the commit markers in the log
+        # ride later forces and may lag it)
+        self.applied_upto: dict[int, int] = {}
         # GC low-water mark: durable entries with index < gc_index discarded
         self._gc_dropped_upto: dict[int, int] = {}
         # per-range GC floor (core/txn.py): records at or above the floor
@@ -122,9 +128,10 @@ class WAL:
         """Scan the durable log for one range.
 
         Returns (records, last_committed_lsn) where `records` excludes
-        logically-truncated LSNs.  In practice all 3 of a node's cohorts are
-        recovered in one shared scan (§6); callers loop over ranges which is
-        observationally identical.
+        logically-truncated LSNs and the commit LSN is the newer of the
+        log's commit markers and the applied point.  In practice all 3 of a
+        node's cohorts are recovered in one shared scan (§6); callers loop
+        over ranges which is observationally identical.
         """
         skipped = self.skipped.get(range_id, set())
         records: list[LogRecord] = []
@@ -135,7 +142,11 @@ class WAL:
                     records.append(e)
             elif isinstance(e, CommitMarker) and e.range_id == range_id:
                 cmt = max(cmt, e.commit_lsn)
-        return records, cmt
+        return records, max(cmt, self.applied_upto.get(range_id, 0))
+
+    def note_applied(self, range_id: int, lsn: int) -> None:
+        """The replica applied its durable log through `lsn`."""
+        self.applied_upto[range_id] = lsn
 
     def seed_range(self, range_id: int, fork_lsn: int) -> None:
         """Durably seed a forked child range's log state (§4-style live
@@ -176,6 +187,7 @@ class WAL:
                         if getattr(p.entry, "range_id", None) != range_id]
         self.skipped.pop(range_id, None)
         self.flushed_upto.pop(range_id, None)
+        self.applied_upto.pop(range_id, None)
         self._gc_dropped_upto.pop(range_id, None)
         self.gc_floor.pop(range_id, None)
 

@@ -26,6 +26,9 @@ Layers (`LAYERS`, indexed by the constants below):
   `types.py`, and the node's thunks that run a replica handler;
 - `client`: `core/cluster.py`, `core/ranges.py`;
 - `workload`: the load drivers and the op stream's host side;
+- `recovery`: a restarted node's local recovery, the catch-up and
+  takeover handlers, and the client's re-sends when a range's leader
+  changes (entered inside the layer that reaches them);
 - `sampler_wait`: from the sampler's dispatch to its outputs being numpy
   arrays on the host;
 - `gc`: the interpreter's cyclic garbage collections, wherever they
@@ -51,9 +54,9 @@ import time
 from typing import Any, Callable
 
 LAYERS = ("sched", "net", "queues", "node", "protocol", "client",
-          "workload", "sampler_wait", "gc", "other")
-(SCHED, NET, QUEUES, NODE, PROTOCOL, CLIENT, WORKLOAD, SAMPLER_WAIT, GC,
- OTHER) = range(len(LAYERS))
+          "workload", "recovery", "sampler_wait", "gc", "other")
+(SCHED, NET, QUEUES, NODE, PROTOCOL, CLIENT, WORKLOAD, RECOVERY, SAMPLER_WAIT,
+ GC, OTHER) = range(len(LAYERS))
 OUTSIDE = len(LAYERS)           # index of the time inside no layer
 COLUMNS = LAYERS + ("outside",)
 BATCH_SPAN = "obs.sampler_batch"
