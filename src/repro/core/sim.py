@@ -288,6 +288,12 @@ class Network:
     Per (src, dst) pair delivery is FIFO: a later send never arrives before
     an earlier one.  Messages to/from a down endpoint are dropped, like a
     broken TCP connection.
+
+    Liveness and partitions are checked at send and again at delivery, so
+    in-flight traffic is cut too.  Every change to that state bumps
+    `_epoch`, and a message carries the epoch of its send: the delivery
+    check runs again only if the epoch moved while the message was in
+    flight, since otherwise it would read the state the send checked.
     """
 
     def __init__(self, sim: Simulator, params: NetParams | None = None):
@@ -301,16 +307,23 @@ class Network:
         self._group: dict[Any, int] = {}   # partition membership
         # one-way partitions: messages src∈A -> dst∈B are blocked, B -> A flow
         self._oneway: list[tuple[frozenset, frozenset]] = []
+        # bumped by every change to `_down`, `_group` or `_oneway`
+        self._epoch = 0
         # per-link gray faults: (src, dst) -> (drop_p, dup_p, delay_factor)
         self._link_faults: dict[tuple[Any, Any], tuple[float, float, float]] = {}
         self.bytes_sent = 0
         self.msgs_sent = 0
         self.msgs_warm = 0      # sends that rode the coalescing path
         self.dropped = 0
+        # deliveries whose epoch had moved, so the fault check ran again
+        self.delivery_rechecks = 0
         # resource profiler attribution (obs/profile.py); accounting only
         self.profiler = None
+        # every delivery event's callback, bound once
+        self._deliver_cb = self._deliver
 
     def set_down(self, endpoint: Any, down: bool = True) -> None:
+        self._epoch += 1
         if down:
             self._down.add(endpoint)
             # connections to/from a dead endpoint reset: reconnection pays
@@ -332,21 +345,25 @@ class Network:
         Endpoints in no group — clients, the coordination service — keep
         full connectivity, mirroring the paper's deployment where ZooKeeper
         sits outside the data path."""
+        self._epoch += 1
         self._group = {}
         for gi, members in enumerate(groups):
             for e in members:
                 self._group[e] = gi
 
     def clear_partition(self) -> None:
+        self._epoch += 1
         self._group = {}
 
     def set_oneway_partition(self, src_group, dst_group) -> None:
         """Block messages from `src_group` to `dst_group` only — the reverse
         direction keeps flowing (asymmetric / gray partition).  Cumulative:
         each call adds one directed cut."""
+        self._epoch += 1
         self._oneway.append((frozenset(src_group), frozenset(dst_group)))
 
     def clear_oneway_partitions(self) -> None:
+        self._epoch += 1
         self._oneway = []
 
     # -- per-link gray faults -------------------------------------------------
@@ -391,13 +408,19 @@ class Network:
         return False
 
     def _blocked(self, src: Any, dst: Any) -> bool:
-        return src in self._down or dst in self._down \
-            or self.partitioned(src, dst)
+        """Whether a fault cuts src -> dst.  With no fault set it reads
+        three empty containers."""
+        down = self._down
+        if down and (src in down or dst in down):
+            return True
+        return bool(self._group or self._oneway) \
+            and self.partitioned(src, dst)
 
     def send(self, src: Any, dst: Any, handler: Callable, *args: Any,
              nbytes: int = 256, cross_switch: bool = False,
              component: Optional[str] = None, rid: Any = None) -> None:
-        hp = self.sim.hostprof
+        sim = self.sim
+        hp = sim.hostprof
         if hp is not None:
             hp.enter(NET)
         if self._blocked(src, dst):
@@ -405,56 +428,82 @@ class Network:
             if hp is not None:
                 hp.leave()
             return  # dropped
-        fault = self._link_faults.get((src, dst))
+        link = (src, dst)
         copies = 1
         delay_factor = 1.0
+        fault = self._link_faults.get(link) if self._link_faults else None
         if fault is not None:
             drop_p, dup_p, delay_factor = fault
-            if drop_p and self.sim.rng.random() < drop_p:
+            if drop_p and sim.rng.random() < drop_p:
                 self.dropped += 1
                 if hp is not None:
                     hp.leave()
                 return  # silently eaten by the flaky link
-            if dup_p and self.sim.rng.random() < dup_p:
+            if dup_p and sim.rng.random() < dup_p:
                 copies = 2
-        prof = self.profiler
+        p = self.p
+        now = sim.now
         # message-coalescing path: a send while the (src, dst) connection is
         # warm is framed onto the in-flight pipeline and pays the propagation
         # floor; the first send after an idle gap pays the full per-message
         # stack overhead (FIFO delivery clamp below keeps ordering intact)
-        link = (src, dst)
         last = self._last_send.get(link)
-        warm = last is not None \
-            and self.sim.now - last <= self.p.stream_idle
-        self._last_send[link] = self.sim.now
-        overhead = self.p.stream_floor if warm else self.p.base_latency
-        if warm:
+        self._last_send[link] = now
+        if last is not None and now - last <= p.stream_idle:
+            overhead = p.stream_floor
             self.msgs_warm += 1
+        else:
+            overhead = p.base_latency
+        # `Simulator.jitter(overhead, p.jitter_cv)`, inlined: the same one
+        # draw per copy, so the random stream is the same
+        cv = p.jitter_cv
+        lo = overhead * max(0.05, 1.0 - 2.0 * cv)
+        prof = self.profiler
+        args = (src, dst, handler, args, self._epoch)
+        staged = sim._staged
         for _ in range(copies):
-            lat = self.sim.jitter(overhead, self.p.jitter_cv)
-            lat += nbytes / self.p.bandwidth
+            if overhead > 0:
+                lat = sim.rng.gauss(overhead, overhead * cv)
+                if lat < lo:
+                    lat = lo
+            else:
+                lat = 0.0
+            lat += nbytes / p.bandwidth
             if cross_switch:
-                lat += self.p.cross_switch_extra
+                lat += p.cross_switch_extra
             lat *= delay_factor
-            key = (src, dst)
-            deliver_at = max(self.sim.now + lat,
-                             self._last_delivery.get(key, 0.0) + 1e-9)
-            self._last_delivery[key] = deliver_at
+            deliver_at = now + lat
+            fifo = self._last_delivery.get(link, 0.0) + 1e-9
+            if fifo > deliver_at:
+                deliver_at = fifo
+            self._last_delivery[link] = deliver_at
             self.bytes_sent += nbytes
             self.msgs_sent += 1
             if prof is not None and prof.enabled:
                 prof.net_msg(src, component or "other", nbytes, rid)
-
-            def deliver():
-                # recheck liveness and partition membership at delivery time
-                if self._blocked(src, dst):
-                    self.dropped += 1
-                    return
-                handler(*args)
-
-            self.sim.at(deliver_at, deliver)
+            # pushed as `Simulator.at(deliver_at, ...)` pushes it: the FIFO
+            # clamp keeps deliver_at >= now, so the delay is deliver_at -
+            # now, and the event's time that delay added back to now
+            ev = Event((now + (deliver_at - now), next(sim._seq),
+                        self._deliver_cb, args))
+            if staged is None:
+                heapq.heappush(sim._heap, ev)
+            else:
+                staged.append(ev)
         if hp is not None:
             hp.leave()
+
+    def _deliver(self, src: Any, dst: Any, handler: Callable, args: tuple,
+                 epoch: int) -> None:
+        """A message's delivery event.  If a fault changed while it was in
+        flight, liveness and partitions are checked again; otherwise they
+        are as its send found them."""
+        if epoch != self._epoch:
+            self.delivery_rechecks += 1
+            if self._blocked(src, dst):
+                self.dropped += 1
+                return
+        handler(*args)
 
 
 @dataclass

@@ -75,13 +75,12 @@ LAYER_OF_MODULE = (
     ("core/coordination.py", NODE),
     ("workload/", WORKLOAD),
 )
-# the event core's closures that run one callback, and the name of the
-# callback among their free variables: an event of theirs is charged to
-# the callback's layer and counted under the callback
-CORE_CLOSURES = {
-    "Network.send.<locals>.deliver": "handler",
-    "FifoServer.submit.<locals>.fire": "cb",
-}
+# the event core's callbacks that run one other callback, an event of
+# which is charged to that callback's layer and counted under it: closures,
+# with the name of the callback among their free variables, and methods,
+# with its position among the event's arguments
+CORE_CLOSURES = {"FifoServer.submit.<locals>.fire": "cb"}
+CORE_METHODS = {"Network._deliver": 2}
 # the node's CPU thunks, which only call a replica handler
 PROTOCOL_THUNKS = ("SpinnakerNode.receive.<locals>.<lambda>",
                    "SpinnakerNode.handle_client.<locals>.<lambda>")
@@ -107,6 +106,19 @@ def layer_of(filename: str, qualname: str) -> int:
     return OTHER
 
 
+def _see_through(code) -> Callable | None:
+    """For an event core callback that runs one other callback, a function
+    of the event's callback and arguments that returns that other one."""
+    name = code.co_qualname
+    if name in CORE_CLOSURES:
+        cell = code.co_freevars.index(CORE_CLOSURES[name])
+        return lambda fn, args: fn.__closure__[cell].cell_contents
+    if name in CORE_METHODS:
+        pos = CORE_METHODS[name]
+        return lambda fn, args: args[pos]
+    return None
+
+
 class HostProfile:
     """Self time per layer, event counts per handler and heap counters,
     between `start` and `stop`."""
@@ -124,12 +136,12 @@ class HostProfile:
         self._stack: list[int] = []
         self._t = 0
         self._t0 = self._t1 = 0
-        # code object (or name) -> [layer, name, events, ns, closure cell]
+        # code object (or name) -> [layer, name, events, ns, see-through]
         self._handlers: dict[Any, list] = {}
         self._sim = None
         self._net = None
         self._disks: tuple = ()
-        self._counters0 = self._counters1 = (0, 0, 0, 0)
+        self._counters0 = self._counters1 = (0, 0, 0, 0, 0)
         self._mark = self._marks()
 
     # -- attach -------------------------------------------------------------
@@ -154,11 +166,12 @@ class HostProfile:
         self._t = self._t1
         self._counters1 = self._counters()
 
-    def _counters(self) -> tuple[int, int, int, int]:
+    def _counters(self) -> tuple[int, int, int, int, int]:
         net = self._net
         return (net.msgs_sent if net is not None else 0,
                 net.bytes_sent if net is not None else 0,
                 net.msgs_warm if net is not None else 0,
+                net.delivery_rechecks if net is not None else 0,
                 sum(d.forces for d in self._disks))
 
     # -- boundaries ---------------------------------------------------------
@@ -183,11 +196,12 @@ class HostProfile:
             self.gc_collections[info["generation"]] += 1
 
     def handler(self, fn: Callable) -> list:
-        """[layer, name, events, ns, closure cell] of a callable, made once
+        """[layer, name, events, ns, see-through] of a callable, made once
         per code object (functions and bound methods carry one; any other
-        callable is keyed by its name); the cell is set for the event
-        core's closures that run one callback, and is that callback's index
-        in the closure."""
+        callable is keyed by its name).  See-through is set for the event
+        core's callbacks that run one other callback (`CORE_CLOSURES`,
+        `CORE_METHODS`): a function of the event's callback and arguments
+        that returns the callback it runs."""
         code = getattr(fn, "__code__", None)
         key = code if code is not None else \
             getattr(fn, "__qualname__", type(fn).__name__)
@@ -197,23 +211,22 @@ class HostProfile:
                 rec = [OTHER, key, 0, 0, None]
             else:
                 name = code.co_qualname
-                cell = CORE_CLOSURES.get(name)
                 rec = [layer_of(code.co_filename, name), name, 0, 0,
-                       code.co_freevars.index(cell) if cell else None]
+                       _see_through(code)]
             self._handlers[key] = rec
         return rec
 
     def dispatch(self, fn: Callable, args: tuple) -> None:
         """Run one event's callback, from the loop's `sched`, under the
         callback's layer, and count the event under its handler.  An event
-        core closure that runs one callback (delivery, CPU completion) is
-        seen through: its event is the callback's."""
+        core callback that runs one other callback (delivery, CPU
+        completion) is seen through: its event is the other callback's."""
         try:
             rec = self._handlers[fn.__code__]
         except (AttributeError, KeyError):
             rec = self.handler(fn)
         if rec[4] is not None:
-            rec = self.handler(fn.__closure__[rec[4]].cell_contents)
+            rec = self.handler(rec[4](fn, args))
         ns = self.ns
         t_in = clock()
         ns[SCHED] += t_in - self._t
@@ -283,7 +296,8 @@ class HostProfile:
             "msgs_sent": m1[0] - m0[0],
             "bytes_sent": m1[1] - m0[1],
             "msgs_warm": m1[2] - m0[2],
-            "disk_forces": m1[3] - m0[3],
+            "delivery_rechecks": m1[3] - m0[3],
+            "disk_forces": m1[4] - m0[4],
             "sampler_batches": self.batches,
             "gc_collections": list(self.gc_collections),
         }

@@ -17,12 +17,13 @@ import pytest
 
 from repro.core import key_of
 from repro.core.node import COMPONENT_OF
+from repro.core.sim import Network, Simulator
 from repro.obs import hostprof
 from repro.obs.hostprof import HostProfile
 from repro.workload.drivers import ClosedLoopDriver, SpinnakerAdapter
 from repro.workload.experiment import ExperimentConfig, build_spinnaker
 from repro.workload.generators import OpStream, WorkloadSpec, _sample_batch
-from repro.workload.metrics import OpLog
+from repro.workload.metrics import LatencyHistogram, OpLog
 
 SPEC = WorkloadSpec(num_keys=200, value_size=512)   # the 80/15/3/2 mix
 
@@ -96,6 +97,8 @@ def test_handler_counts_sum_to_events_processed(s9_run):
     assert 0 < sum(s["msgs_by_component"].values()) < s["msgs_sent"]
     assert s["disk_forces"] > 0 and s["bytes_sent"] > 0
     assert 0 < s["msgs_warm"] <= s["msgs_sent"]
+    # no fault changed, so no delivery checked liveness again
+    assert s["delivery_rechecks"] == 0
 
 
 def test_every_dispatched_handler_has_a_named_layer(s9_run):
@@ -119,6 +122,7 @@ def test_every_dispatched_handler_has_a_named_layer(s9_run):
 @pytest.mark.parametrize("filename,qualname,layer", [
     ("core/sim.py", "Simulator.run", hostprof.SCHED),
     ("core/sim.py", "Network.send.<locals>.deliver", hostprof.NET),
+    ("core/sim.py", "Network._deliver", hostprof.NET),
     ("core/sim.py", "FifoServer.submit.<locals>.fire", hostprof.QUEUES),
     ("core/sim.py", "Disk._start_batch.<locals>.done", hostprof.QUEUES),
     ("core/node.py", "SpinnakerNode.receive", hostprof.NODE),
@@ -137,6 +141,30 @@ def test_layer_of_module(filename, qualname, layer):
     assert hostprof.layer_of(f"{pkg}/{filename}", qualname) == layer
     assert hostprof.layer_of(f"/elsewhere/{filename}", qualname) \
         == hostprof.OTHER
+
+
+def test_a_delivery_is_charged_to_and_counted_under_its_handler():
+    sim = Simulator(seed=4)
+    net = Network(sim)
+    hist = LatencyHistogram()               # a workload-layer handler
+    hp = HostProfile().start(sim, net)
+    for i in range(300):
+        net.send(i % 3, 3, hist.add, 1e-3 * i)
+    sim.run()
+    net.set_down(3)
+    net.send(0, 3, hist.add, 1.0)            # dropped at send
+    net.send(0, 4, hist.add, 2.0)
+    net.set_down(1)                          # in flight: rechecked
+    sim.run()
+    hp.stop()
+    s = hp.summary()
+    names = dict(s["handlers_by_count"])
+    assert names == {"LatencyHistogram.add": 301}
+    assert hist.total == 301
+    assert s["delivery_rechecks"] == 1 and s["msgs_sent"] == 301
+    assert s["self_ns"]["workload"] > 0 and s["self_ns"]["net"] > 0
+    assert s["self_ns"]["other"] == 0
+    assert sum(s["self_ns"].values()) == s["wall_ns"]
 
 
 def _fixed_run(profile):
